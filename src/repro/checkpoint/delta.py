@@ -10,8 +10,7 @@ for the training instantiation we additionally compress successive versions:
     moment spans ~8 orders of magnitude and sits next to first-moment blocks
     in any flat stream; block-quantizing its deltas rounds small v entries
     to zero and the next update explodes (m/(sqrt(0)+eps)). Measured before
-    this split: post-restore loss 6.2 -> 13+. Lesson recorded in
-    EXPERIMENTS.md §Perf (training substrate).
+    this split: post-restore loss 6.2 -> 13+.
 
 Restore replays base + deltas for params and loads moments directly.
 """
@@ -25,6 +24,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..kernels import ops as kops
+from ..kernels.delta_encode import ROWS as _ROWS
 
 _BLOCK = 1024
 
@@ -50,18 +50,23 @@ def _unflatten(flat: np.ndarray, shapes, treedef):
     return jax.tree_util.tree_unflatten(treedef, out)
 
 
+def padded_blocks(n: int) -> int:
+    """Rows of ``_BLOCK`` floats holding ``n`` values, rounded up to the
+    kernel's row tile so every grid step is a full tile."""
+    nb = max(1, -(-n // _BLOCK))
+    return -(-nb // _ROWS) * _ROWS
+
+
 def _pad_blocks(flat: np.ndarray) -> np.ndarray:
-    n = len(flat)
-    nb = max(1, (n + _BLOCK - 1) // _BLOCK)
+    nb = padded_blocks(len(flat))
     padded = np.zeros(nb * _BLOCK, np.float32)
-    padded[:n] = flat
+    padded[: len(flat)] = flat
     return padded.reshape(nb, _BLOCK)
 
 
 class DeltaCheckpointCodec:
-    def __init__(self, base_every: int = 8, use_kernel: bool = True) -> None:
+    def __init__(self, base_every: int = 8) -> None:
         self.base_every = base_every
-        self.use_kernel = use_kernel
 
     def encode(self, version: int, state, prev_flat: Optional[np.ndarray]):
         """state = (params, opt_state). Returns (blob, new_params_flat).
@@ -69,17 +74,12 @@ class DeltaCheckpointCodec:
         params, opt = state
         p_flat, _, _ = _flatten(params)
         o_leaves, _ = jax.tree_util.tree_flatten(opt)
-        opt_arrays: Dict[str, np.ndarray] = {}
-        for i, leaf in enumerate(o_leaves):
-            a = np.asarray(leaf)
-            if a.dtype == np.float32 and a.ndim >= 1 and "m" not in opt_arrays:
-                pass  # dtype policy handled below per leaf index
-            opt_arrays[f"o{i}"] = a
-        # dtype policy: fp32 leaves of the FIRST moment tree -> fp16; the
-        # rest (v, step) stay at full precision. The opt dict layout is
-        # {"m": tree, "v": tree, "step": scalar}; flatten order is m*, step, v*
-        # — we conservatively detect by magnitude instead: fp16 only when the
-        # leaf round-trips within 1e-3 relative error.
+        opt_arrays: Dict[str, np.ndarray] = {
+            f"o{i}": np.asarray(leaf) for i, leaf in enumerate(o_leaves)
+        }
+        # dtype policy: an fp32 moment leaf is stored as fp16 only when it
+        # round-trips within 1e-3 relative error (in practice the first
+        # moment); the rest (v, step) stay at full precision.
         for k, a in list(opt_arrays.items()):
             if a.dtype == np.float32:
                 a16 = a.astype(np.float16)
@@ -92,20 +92,10 @@ class DeltaCheckpointCodec:
         if is_base:
             np.savez_compressed(buf, kind=np.array(0), flat=p_flat, **opt_arrays)
         else:
-            new_b = _pad_blocks(p_flat)
-            prev_b = _pad_blocks(prev_flat)
-            if self.use_kernel:
-                codes, scales = kops.delta_encode(
-                    jnp.asarray(new_b), jnp.asarray(prev_b), interpret=True
-                )
-                codes, scales = np.asarray(codes), np.asarray(scales)
-            else:
-                from ..kernels import ref
-
-                codes, scales = ref.delta_encode_ref(
-                    jnp.asarray(new_b), jnp.asarray(prev_b)
-                )
-                codes, scales = np.asarray(codes), np.asarray(scales)
+            codes, scales = kops.delta_encode(
+                jnp.asarray(_pad_blocks(p_flat)), jnp.asarray(_pad_blocks(prev_flat))
+            )
+            codes, scales = np.asarray(codes), np.asarray(scales)
             np.savez_compressed(
                 buf, kind=np.array(1), codes=codes, scales=scales,
                 n=np.array(len(p_flat)), **opt_arrays,
@@ -128,7 +118,7 @@ class DeltaCheckpointCodec:
                 prev_b = _pad_blocks(flat)
                 dec = kops.delta_decode(
                     jnp.asarray(z["codes"]), jnp.asarray(z["scales"]),
-                    jnp.asarray(prev_b), dtype=jnp.float32, interpret=True,
+                    jnp.asarray(prev_b), dtype=jnp.float32,
                 )
                 flat = np.asarray(dec).ravel()[: int(z["n"])]
         assert flat is not None and last is not None

@@ -288,6 +288,12 @@ class DSERuntime:
     # ------------------------------------------------------------------ #
     # persistence (group commit)                                         #
     # ------------------------------------------------------------------ #
+    @property
+    def dirty(self) -> bool:
+        """True when an action has run since the last persist began."""
+        with self._mu:
+            return self._dirty
+
     def maybe_persist(self, force: bool = False) -> Optional[int]:
         self._check_alive()
         now = self.clock.now()

@@ -124,6 +124,11 @@ class StateObject(abc.ABC):
         return self._runtime is not None
 
 
+#: bytes per write call, so a crashed incarnation's in-flight version stops
+#: within one chunk
+_WRITE_CHUNK = 64 << 20
+
+
 class VersionStore:
     """Durable multi-version blob store with an in-memory fast tier.
 
@@ -177,12 +182,18 @@ class VersionStore:
                 import time
 
                 time.sleep(self._simulate_io_ms / 1e3)
-        tmp = self.root / f".v{version}.tmp"
+        # per-store temp name: a crashed incarnation's store unlinks its own
+        # temp file, never the one its replacement writes for the same label
+        tmp = self.root / f".v{version}.{id(self):x}.tmp"
         final = self.root / f"v{version}.blob"
         with open(tmp, "wb") as f:
             f.write(len(metadata).to_bytes(8, "little"))
             f.write(metadata)
-            f.write(payload)
+            view = memoryview(payload)
+            for off in range(0, len(view), _WRITE_CHUNK):
+                if self._poisoned:
+                    break  # a crashed incarnation stops writing mid-version
+                f.write(view[off : off + _WRITE_CHUNK])
             f.flush()
             os.fsync(f.fileno())
         if self._poisoned:

@@ -1,7 +1,8 @@
 """Jitted public wrappers for the Pallas kernels.
 
-``interpret`` defaults to True off-TPU (this container), so the kernels are
-validated on CPU; on TPU the same call sites compile the Mosaic kernels.
+``interpret`` is chosen here and nowhere else: on a TPU the call sites
+compile the Mosaic kernels, on the CPU backend (the test suite) they run in
+the Pallas interpreter, and any other backend is an error.
 Model code selects ``attn_impl``/``ssd_impl`` in {"xla", "pallas"}; the
 dry-run/roofline path uses "xla" so HLO cost analysis reflects the
 production XLA pipeline (see DESIGN.md §5).
@@ -19,7 +20,13 @@ from . import ssd as _ssd
 
 
 def _default_interpret() -> bool:
-    return jax.default_backend() != "tpu"
+    backend = jax.default_backend()
+    if backend not in ("tpu", "cpu"):
+        raise RuntimeError(
+            f"Pallas TPU kernels compile only for a TPU (or interpret on the "
+            f"CPU); the default backend is {backend!r}"
+        )
+    return backend == "cpu"
 
 
 @functools.partial(jax.jit, static_argnames=("causal", "block_q", "block_k", "interpret"))

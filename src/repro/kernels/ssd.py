@@ -18,8 +18,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 import jax.experimental.pallas.tpu as pltpu
 
-from ._compat import CompilerParams as _CompilerParams
-
 
 def _ssd_kernel(
     a_ref,                       # (1,) per-head A (negative), SMEM-ish block
@@ -111,7 +109,7 @@ def ssd(
         out_specs=pl.BlockSpec((1, 1, chunk, p), lambda ib, ih, ic: (ib, ih, ic, 0)),
         out_shape=jax.ShapeDtypeStruct((b, h, nc * chunk, p), x.dtype),
         scratch_shapes=[pltpu.VMEM((p, n), jnp.float32)],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,

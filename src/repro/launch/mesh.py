@@ -28,6 +28,6 @@ def make_host_mesh(model: int = 1):
 TPU_V5E = {
     "peak_flops_bf16": 197e12,   # FLOP/s
     "hbm_bw": 819e9,             # B/s
-    "ici_link_bw": 50e9,         # B/s per link (~; see EXPERIMENTS.md)
+    "ici_link_bw": 50e9,         # B/s per link (approximate)
     "hbm_bytes": 16 * 2**30,
 }

@@ -1,11 +1,7 @@
-"""Training launcher.
-
-Local mode (this container): runs the DSE-resilient training loop on a
-reduced config with optional failure injection.
-
-Cluster mode (TPU pods): the same entry point would initialize
-jax.distributed and build the production mesh; per-host process launch is
-scripts/launch_pod.sh. On CPU we validate the mesh path via the dry-run.
+"""Training launcher: runs the DSE-resilient training loop on one device,
+with optional failure injection. The default is the architecture's reduced
+smoke config (CPU-sized); ``--full-config`` uses the published widths,
+which at mamba2-370m fit one TPU v5e (``chip_smoke.py`` drives that path).
 
 Usage:
   PYTHONPATH=src python -m repro.launch.train --arch gemma-2b --steps 20 \
@@ -35,8 +31,10 @@ def main() -> None:
     args = ap.parse_args()
 
     from repro.configs import get_config
+    from repro.launch.compile_cache import enable_compile_cache
     from repro.train import run_resilient_training
 
+    enable_compile_cache()
     cfg = get_config(args.arch, smoke=not args.full_config)
     res = run_resilient_training(
         Path(args.out),

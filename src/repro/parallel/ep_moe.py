@@ -108,8 +108,6 @@ def ep_moe(p: Dict[str, jax.Array], x: jax.Array, cfg: ModelConfig):
     observed as a 2x compute regression on deepseek before this layout).
     Experts pad up to a multiple of |model| (granite: 40 -> 48); padded
     experts are never routed to."""
-    from jax.experimental.shard_map import shard_map
-
     mesh = get_ep_mesh()
     assert mesh is not None, "ep_moe requires an ep_mesh(...) context"
     batch_axes = tuple(a for a in ("pod", "data") if a in mesh.axis_names)
@@ -138,7 +136,7 @@ def ep_moe(p: Dict[str, jax.Array], x: jax.Array, cfg: ModelConfig):
         aux = jax.lax.pmean(aux, batch_axes + ("model",))
         return y.reshape(xb.shape), aux
 
-    y, aux = shard_map(
+    y, aux = jax.shard_map(
         body,
         mesh=mesh,
         in_specs=(
@@ -149,6 +147,6 @@ def ep_moe(p: Dict[str, jax.Array], x: jax.Array, cfg: ModelConfig):
             P("model", None, None),
         ),
         out_specs=(x_spec, P()),
-        check_rep=False,
+        check_vma=False,
     )(x, p["router"], wg, wu, wd)
     return y, aux

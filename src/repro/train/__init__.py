@@ -1,3 +1,3 @@
-from .loop import TrainRunResult, run_resilient_training
+from .loop import TrainRunResult, init_train_state, run_resilient_training, train_step_fn
 
-__all__ = ["TrainRunResult", "run_resilient_training"]
+__all__ = ["TrainRunResult", "init_train_state", "run_resilient_training", "train_step_fn"]

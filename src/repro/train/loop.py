@@ -39,6 +39,26 @@ class TrainRunResult:
     external_metrics: List[Tuple[int, float]]
     rollbacks: int
     checkpoint_bytes: int
+    #: trainer step observed right after each recovery (the restored step)
+    restored_to: List[int] = field(default_factory=list)
+    #: (step, snapshot seconds, seconds until durable) per trainer save,
+    #: host wall-clock timings
+    saves: List[Tuple[int, float, float]] = field(default_factory=list)
+
+
+def train_step_fn(cfg: ModelConfig, lr: float = 1e-3):
+    """The loop's jitted train step: (params, opt, batch) -> (params, opt,
+    loss), with full rematerialization. Params and optimizer state are
+    donated: ``train_on`` replaces them with the outputs, and ``Persist``
+    copies them to the host under the exclusive epoch, before any later step
+    can consume them."""
+    return jax.jit(make_train_step(cfg, AdamWConfig(lr=lr)), donate_argnums=(0, 1))
+
+
+def init_train_state(cfg: ModelConfig, seed: int = 0):
+    """Seeded float32 parameters and their AdamW state."""
+    params = init_params(param_descs(cfg), jax.random.key(seed), dtype=jax.numpy.float32)
+    return params, adamw_init(params)
 
 
 def run_resilient_training(
@@ -56,14 +76,10 @@ def run_resilient_training(
     lr: float = 1e-3,
 ) -> TrainRunResult:
     data = SyntheticLMData(cfg.vocab_size, global_batch, seq_len, seed=seed)
-    opt_cfg = AdamWConfig(lr=lr)
-    step_fn = jax.jit(make_train_step(cfg, opt_cfg, remat="none"))
-
-    def init_state():
-        params = init_params(param_descs(cfg), jax.random.key(seed), dtype=jax.numpy.float32)
-        return params, adamw_init(params)
-
+    step_fn = train_step_fn(cfg, lr)
+    init_state = lambda: init_train_state(cfg, seed)
     codec = DeltaCheckpointCodec(base_every=4) if use_delta_codec else None
+    saves: List[Tuple[int, float, float]] = []
 
     with LocalCluster(root, group_commit_interval=group_commit_interval) as cluster:
         data_so = cluster.add(
@@ -71,11 +87,14 @@ def run_resilient_training(
         )
         trainer = cluster.add(
             "trainer",
-            lambda: TrainerStateObject(Path(root) / "trainer", init_state, step_fn, codec=codec),
+            lambda: TrainerStateObject(
+                Path(root) / "trainer", init_state, step_fn, codec=codec, save_log=saves
+            ),
         )
         metrics = cluster.add("metrics", lambda: MetricsStateObject(Path(root) / "metrics"))
 
         rollbacks = 0
+        restored_to: List[int] = []
         steps_run = 0
         last_world = 0
         while True:
@@ -85,6 +104,7 @@ def run_resilient_training(
             if trainer.runtime.world > last_world:  # a recovery happened
                 rollbacks += trainer.runtime.world - last_world
                 last_world = trainer.runtime.world
+                restored_to.append(trainer.current_step())
             t_step = trainer.current_step()
             if t_step >= steps:
                 break
@@ -156,4 +176,6 @@ def run_resilient_training(
             external_metrics=external,
             rollbacks=rollbacks,
             checkpoint_bytes=trainer.bytes_written,
+            restored_to=restored_to,
+            saves=saves,
         )

@@ -72,7 +72,7 @@ class DecodeSessionStateObject(StateObject):
 
     def Restore(self, version: int) -> bytes:
         payload, meta = self.store.read(version)
-        self.tokens = list(np.frombuffer(payload, np.int32))
+        self.tokens = np.frombuffer(payload, np.int32).tolist()
         self._rebuild_cache()
         return meta
 

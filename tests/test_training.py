@@ -68,6 +68,23 @@ def test_delta_codec_preserves_state(tmp_path):
     assert len(delta.external_metrics) == STEPS
 
 
+def test_snapshot_blob_opens_as_zip64_archive(tmp_path):
+    """A full-width snapshot is over 4 GB, so its archive carries zip64
+    records, whose offsets are absolute: the archive must start the body
+    Restore loads. More than 65535 leaves force those records at a tiny size."""
+    import io
+
+    from repro.checkpoint import TrainerStateObject
+
+    leaves = [np.full(2, i, np.float32) for i in range(66_000)]
+    so = TrainerStateObject(tmp_path, lambda: (leaves, []), step_fn=None)
+    hdr, body = so._split_blob(so._snapshot_blob(1))
+    assert hdr["step"] == 0 and hdr["base"]
+    archive = np.load(io.BytesIO(body))
+    assert len(archive.files) == 66_000
+    assert float(archive[archive.files[-1]][0]) == 65_999.0
+
+
 def test_gradient_compression_error_feedback():
     from repro.optim import compress_gradients_int8, decompress_gradients_int8
 
