@@ -14,13 +14,13 @@ Phases, in one process, the short ones first:
   serve      run_speculative_serving twice, failure-free and with the
              session killed mid-stream. The durable token streams must be
              equal.
-  train      run_resilient_training with the trainer killed after a save
-             and resumed by Restore from that save. Its losses and digest
-             must equal the reference's.
+  train      run_resilient_training twice: failure-free, and with the
+             trainer killed after a save and resumed by Restore from that
+             save. Both runs' losses and digests must equal each other's
+             and the reference's.
 
-The failure-free training run is the plain step loop, not a second DSE run:
-a full-width save writes 5.06 GB to disk and the killed run alone makes
-about six, so a second run would double what the smoke writes.
+A full-width save writes 5.06 GB to disk, so the failure-free run saves only
+at Connect and at the end, and the killed run about five times.
 
 Exits non-zero before any phase unless JAX's first device is a TPU. With
 CHIP_SMOKE_REHEARSAL=1 it runs the same phases on the CPU at the smoke
@@ -48,13 +48,16 @@ sys.path.insert(0, str(ROOT / "src"))
 REHEARSAL = os.environ.get("CHIP_SMOKE_REHEARSAL") == "1"
 SEED = 0
 BATCH, SEQ = (2, 32) if REHEARSAL else (4, 1024)
-# A group commit as soon as state is dirty saves the trainer after every
-# step, so which save is durable at the kill follows from the step count,
-# not from wall time: the save after step 0 is written while the save after
-# step 1 is being snapshotted (a 5 GB save is written faster than it is
-# snapshotted), so killing after step 2 resumes from step 1.
 STEPS, KILL_AT = 3, 3
-GROUP_COMMIT_S = 0.0
+# The failure-free run's group commit never falls due, so it saves twice:
+# at Connect and at the end.
+FREE_GROUP_COMMIT_S = 3600.0
+# The killed run commits as soon as state is dirty, saving the trainer after
+# every step, so which save is durable at the kill follows from the step
+# count, not from wall time: the save after step 0 is written while the save
+# after step 1 is being snapshotted (a 5 GB save is written faster than it
+# is snapshotted), so killing after step 2 resumes from step 1.
+KILLED_GROUP_COMMIT_S = 0.0
 SERVE_TOKENS, SERVE_KILL_AT = 16, 8
 RUN_DIR = ROOT / ".chip_smoke"
 
@@ -172,38 +175,53 @@ def phase_serve(jax, cfg) -> None:
     log("[serve] ok: the killed session's durable tokens equal the failure-free ones")
 
 
-def phase_train(jax, cfg, ref_run: dict) -> None:
+def train_run(cfg, name: str, kill_at, group_commit_s: float):
     from repro.train import run_resilient_training
 
     t0 = time.perf_counter()
     res = run_resilient_training(
-        RUN_DIR / "train", cfg, steps=STEPS, global_batch=BATCH, seq_len=SEQ,
-        kill_trainer_at=KILL_AT, group_commit_interval=GROUP_COMMIT_S, seed=SEED,
+        RUN_DIR / f"train-{name}", cfg, steps=STEPS, global_batch=BATCH,
+        seq_len=SEQ, kill_trainer_at=kill_at, group_commit_interval=group_commit_s,
+        seed=SEED,
     )
     drain_io()
+    shutil.rmtree(RUN_DIR / f"train-{name}")  # 5 GB a save: free the disk now
     losses = [l for _, l in sorted(res.external_metrics)]
-    log(f"[train] killed after step {KILL_AT - 1}: final_step={res.final_step} "
-        f"rollbacks={res.rollbacks} restored_to={res.restored_to} "
-        f"digest={res.params_digest} wall {time.perf_counter() - t0:.1f} s (host)")
-    log(f"[train] losses {losses}")
+    log(f"[train] {name}: kill_trainer_at={kill_at} group_commit={group_commit_s} s "
+        f"final_step={res.final_step} rollbacks={res.rollbacks} "
+        f"restored_to={res.restored_to} digest={res.params_digest} "
+        f"wall {time.perf_counter() - t0:.1f} s (host)")
+    log(f"[train] {name}: losses {losses}")
     for step, snap_s, durable_s in res.saves:
-        log(f"[train] save at step {step}: snapshot {snap_s:.2f} s, "
+        log(f"[train] {name}: save at step {step}: snapshot {snap_s:.2f} s, "
             f"durable after {durable_s:.2f} s (host wall-clock)")
-    log(f"[train] {len(res.saves)} saves durable, {res.checkpoint_bytes} bytes")
-
-    check(res.final_step == STEPS, f"run ended at step {res.final_step}")
+    log(f"[train] {name}: {len(res.saves)} saves durable; the last trainer "
+        f"incarnation wrote {res.checkpoint_bytes} bytes")
+    check(res.final_step == STEPS, f"{name} run ended at step {res.final_step}")
     check(sorted(s for s, _ in res.external_metrics) == list(range(STEPS)),
-          f"metrics exported for steps {res.external_metrics}")
-    check(res.rollbacks >= 1, "the kill did not roll the trainer back")
-    check(bool(res.restored_to) and min(res.restored_to) > 0,
-          f"resumed from step {res.restored_to}: no save after step 0 was "
+          f"{name} run exported metrics for steps {res.external_metrics}")
+    check(all(math.isfinite(l) for l in losses), f"non-finite loss in {losses}")
+    return res, losses
+
+
+def phase_train(jax, cfg, ref_run: dict) -> None:
+    free, free_losses = train_run(cfg, "failure-free", None, FREE_GROUP_COMMIT_S)
+    check(free.rollbacks == 0, f"failure-free run rolled back {free.rollbacks} times")
+    killed, killed_losses = train_run(cfg, "killed", KILL_AT, KILLED_GROUP_COMMIT_S)
+    check(killed.rollbacks >= 1, "the kill did not roll the trainer back")
+    check(bool(killed.restored_to) and min(killed.restored_to) > 0,
+          f"resumed from step {killed.restored_to}: no save after step 0 was "
           "durable before the kill")
-    check(losses == ref_run["losses"],
-          f"losses {losses} differ from the reference {ref_run['losses']}")
-    check(res.params_digest == ref_run["digest"],
-          f"digest {res.params_digest}, reference {ref_run['digest']}")
-    log(f"[train] ok: resumed from step {res.restored_to} and ended at the "
-        f"reference digest {ref_run['digest']}")
+    check(killed.params_digest == free.params_digest,
+          f"killed run's digest {killed.params_digest}, failure-free {free.params_digest}")
+    check(killed_losses == free_losses,
+          f"killed run's losses {killed_losses}, failure-free {free_losses}")
+    check(free.params_digest == ref_run["digest"] and free_losses == ref_run["losses"],
+          f"DSE runs end at {free.params_digest} with losses {free_losses}; "
+          f"the plain step loop at {ref_run['digest']} with {ref_run['losses']}")
+    log(f"[train] ok: the killed run resumed from step {killed.restored_to} by Restore "
+        f"and ended at the failure-free digest {free.params_digest}, which the "
+        "plain step loop reaches too")
 
 
 def main() -> None:

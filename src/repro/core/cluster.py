@@ -230,10 +230,10 @@ class LocalCluster:
         for so in sos:
             try:
                 rt = so.runtime
-                if rt.dirty:
-                    labels.append((so, rt.maybe_persist(force=True)))
-                else:
-                    labels.append((so, max(rt.stats()["labels"], default=None)))
+                label = rt.persist_if_dirty()
+                if label is None:
+                    label = max(rt.stats()["labels"], default=None)
+                labels.append((so, label))
             except Exception:
                 labels.append((so, None))
         deadline = self.clock.now() + 3.0

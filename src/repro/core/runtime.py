@@ -288,12 +288,6 @@ class DSERuntime:
     # ------------------------------------------------------------------ #
     # persistence (group commit)                                         #
     # ------------------------------------------------------------------ #
-    @property
-    def dirty(self) -> bool:
-        """True when an action has run since the last persist began."""
-        with self._mu:
-            return self._dirty
-
     def maybe_persist(self, force: bool = False) -> Optional[int]:
         self._check_alive()
         now = self.clock.now()
@@ -301,10 +295,25 @@ class DSERuntime:
             due = (now - self._last_persist) >= self.config.group_commit_interval
             if not force and not (due and self._dirty):
                 return None
-        return self._persist_now()
+        return self._persist_now(only_if_dirty=not force)
 
-    def _persist_now(self, force_label: Optional[int] = None, synchronous: bool = False) -> int:
-        label, done, _world = self._persist_begin(force_label)
+    def persist_if_dirty(self) -> Optional[int]:
+        """Persist now, whatever the group-commit interval, if an action ran
+        since the last persist began; returns the label, or None when there
+        was nothing to persist."""
+        self._check_alive()
+        return self._persist_now(only_if_dirty=True)
+
+    def _persist_now(
+        self,
+        force_label: Optional[int] = None,
+        synchronous: bool = False,
+        only_if_dirty: bool = False,
+    ) -> Optional[int]:
+        begun = self._persist_begin(force_label, only_if_dirty)
+        if begun is None:
+            return None
+        label, done, _world = begun
         if synchronous:
             done.wait()
             try:
@@ -313,16 +322,23 @@ class DSERuntime:
                 pass  # connect-time flush: requeued, retried next Refresh
         return label
 
-    def _persist_begin(self, force_label: Optional[int] = None):
+    def _persist_begin(self, force_label: Optional[int] = None, only_if_dirty: bool = False):
         """Snapshot + kick off the async Persist IO; returns ``(label,
         done_event, world)`` — the event sets once the version is durable
         and its report is queued; ``world`` is the epoch the snapshot (and
         its report) actually carries, taken under the exclusive epoch so no
         decision can interleave. The synchronous durable baseline builds
-        its per-action commit wait on this hook."""
+        its per-action commit wait on this hook.
+
+        With ``only_if_dirty`` it returns None and persists nothing when no
+        action ran since the last persist began: dirtiness is re-checked
+        under the exclusive epoch, because a concurrent persist may have
+        taken the state while this caller waited for the epoch."""
         self._epoch.acquire_exclusive()
         try:
             with self._mu:
+                if only_if_dirty and not self._dirty:
+                    return None
                 label = self._v_cur if force_label is None else force_label
                 deps = frozenset(self._current_deps)
                 self._current_deps = set()

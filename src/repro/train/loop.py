@@ -29,6 +29,10 @@ from ..models.config import ModelConfig
 from ..optim import AdamWConfig, adamw_init
 from ..launch.steps import make_train_step
 
+#: how long the final metrics export may wait for the last saves to become
+#: durable
+FINAL_SAVE_TIMEOUT_S = 600.0
+
 
 @dataclass
 class TrainRunResult:
@@ -152,20 +156,26 @@ def run_resilient_training(
                 cluster.kill("data")
                 kill_data_at = None
 
-        # force final durability, reconcile any metric dropped by a late
-        # rollback (the refresher applies decisions asynchronously), then
-        # export only non-speculative metrics
+        # reconcile any metric dropped by a late rollback (the refresher
+        # applies decisions asynchronously), make every member's last action
+        # durable, then export only non-speculative metrics. The history read
+        # is itself a trainer action, so it is taken only when a record is
+        # missing, and each member saves only if an action ran since its last
+        # save began: at full width a trainer save is 5 GB.
         trainer = cluster.get("trainer")
         metrics = cluster.get("metrics")
-        trainer.runtime.maybe_persist(force=True)
-        snap = trainer.history_snapshot()
-        if snap is not None:
-            history, hh = snap
-            have = {s for s, _ in metrics.records}
-            for s, l in history:
-                if s not in have:
-                    metrics.record(s, l, hh)
-        external = metrics.flush_external()
+        have = {s for s, _ in metrics.records}
+        if not have.issuperset(range(trainer.current_step())):
+            snap = trainer.history_snapshot()
+            if snap is not None:
+                history, hh = snap
+                for s, l in history:
+                    if s not in have:
+                        metrics.record(s, l, hh)
+        for so_id in ("data", "trainer"):
+            cluster.get(so_id).runtime.persist_if_dirty()
+        # the barrier waits out a whole trainer save (~25 s at full width)
+        external = metrics.flush_external(timeout=FINAL_SAVE_TIMEOUT_S)
         recorded = list(metrics.records)
 
         return TrainRunResult(

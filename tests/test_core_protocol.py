@@ -7,6 +7,7 @@ partition rule across failure epochs, and coordinator failure/recovery.
 """
 from __future__ import annotations
 
+import threading
 import time
 
 import pytest
@@ -194,6 +195,34 @@ class TestGroupCommit:
         c = cluster_factory(refresh_interval=None, group_commit_interval=999)
         so = c.add("g", make_counter(tmp_path, "g"))
         assert so.runtime.maybe_persist(force=True) is not None
+
+    def test_persist_if_dirty_ignores_interval_not_cleanliness(self, cluster_factory, tmp_path):
+        c = cluster_factory(refresh_interval=None, group_commit_interval=999)
+        so = c.add("g", make_counter(tmp_path, "g"))
+        assert so.runtime.persist_if_dirty() is None  # v0 holds the state
+        so.increment(None)
+        assert so.runtime.persist_if_dirty() == 1
+        assert so.runtime.persist_if_dirty() is None
+
+    def test_queued_group_commits_persist_once(self, cluster_factory, tmp_path):
+        # two group commits find the state dirty while an action holds the
+        # epoch; the one that gets the exclusive epoch second finds the state
+        # already taken and persists nothing
+        c = cluster_factory(refresh_interval=None, group_commit_interval=0.0)
+        so = c.add("g", make_counter(tmp_path, "g"))
+        assert so.StartAction(None)
+        labels = []
+        commits = [
+            threading.Thread(target=lambda: labels.append(so.runtime.maybe_persist()))
+            for _ in range(2)
+        ]
+        for t in commits:
+            t.start()
+        time.sleep(0.1)  # both pass the dirty check and queue on the epoch
+        so.EndAction()
+        for t in commits:
+            t.join(timeout=10)
+        assert sorted(labels, key=str) == [1, None]
 
 
 # --------------------------------------------------------------------------- #

@@ -56,6 +56,15 @@ def test_data_pipeline_failure_recovers(tmp_path):
     assert injected.params_digest == base.params_digest
 
 
+def test_run_without_group_commit_saves_at_connect_and_end(tmp_path):
+    """A group commit that never falls due leaves durability to the end of
+    the run: every member is saved there, each at most once, and the export
+    sees every step."""
+    res = run_resilient_training(tmp_path / "g", CFG, steps=3, group_commit_interval=3600.0)
+    assert [step for step, _, _ in res.saves] == [0, 3]
+    assert sorted(s for s, _ in res.external_metrics) == [0, 1, 2]
+
+
 def test_delta_codec_preserves_state(tmp_path):
     base = run_resilient_training(tmp_path / "b3", CFG, steps=STEPS)
     delta = run_resilient_training(
