@@ -27,12 +27,14 @@ import io
 import json
 import threading
 import time
+import zipfile
 from pathlib import Path
 from typing import Callable, Dict, List, Optional, Tuple
 
 import jax
 import numpy as np
 
+from ..core import spans
 from ..core.ids import Header
 from ..core.state_object import StateObject, VersionStore
 from .delta import DeltaCheckpointCodec, _flatten
@@ -57,7 +59,12 @@ class TrainerStateObject(StateObject):
         # one version is the whole training state (5 GB at mamba2-370m in
         # fp32 with Adam), so the memory tier keeps only the newest
         self.store = VersionStore(root, keep_in_memory=1)
-        self.params, self.opt_state = init_state_fn()
+        with spans.span("trainer.init"):
+            self.params, self.opt_state = init_state_fn()
+            if spans.enabled():
+                # the span ends with the state made; unrecorded, the first
+                # use of the state waits for it instead
+                jax.block_until_ready((self.params, self.opt_state))
         self.step_fn = step_fn
         self.step = 0
         # loss history is part of trainer state: it rolls back and replays
@@ -100,12 +107,22 @@ class TrainerStateObject(StateObject):
             self._last_label = version
             return self._header(prev_label, force_base) + body
         # Stored uncompressed: zlib shrinks float32 state by ~7% at ~24 MB/s
-        # of host CPU, minutes per save at full width. np.savez copies one
-        # leaf at a time to the host. The archive starts its own buffer:
-        # zip64 records (archives over 4 GB) hold absolute offsets.
+        # of host CPU, minutes per save at full width. The archive is what
+        # np.savez writes (stored zip, zip64, ``arr_<i>.npy`` in leaf order),
+        # written one leaf at a time: a leaf's copy to the host, then its
+        # write into the archive. The archive starts its own buffer: zip64
+        # records (archives over 4 GB) hold absolute offsets.
         buf = io.BytesIO()
-        np.savez(buf, *jax.tree_util.tree_leaves(state))
-        return self._header(None, True) + buf.getbuffer()
+        with zipfile.ZipFile(buf, "w", zipfile.ZIP_STORED, allowZip64=True) as archive:
+            for i, leaf in enumerate(jax.tree_util.tree_leaves(state)):
+                with spans.span("trainer.fetch", leaf=i):
+                    arr = np.asarray(leaf)
+                with spans.span("trainer.encode", leaf=i), \
+                        archive.open(f"arr_{i}.npy", "w", force_zip64=True) as f:
+                    np.lib.format.write_array(f, arr, allow_pickle=False)
+                del arr
+        with spans.span("trainer.join"):
+            return self._header(None, True) + buf.getbuffer()
 
     @staticmethod
     def _split_blob(blob: bytes):
@@ -136,39 +153,43 @@ class TrainerStateObject(StateObject):
 
     def Restore(self, version: int) -> bytes:
         payload, meta = self.store.read(version)
-        hdr, body = self._split_blob(payload)
-        del payload  # body is a copy: hold one, not two, of a 5 GB state
-        if self.codec is not None:
-            # walk explicit parent pointers down to a base (stale blobs from
-            # rolled-back label ranges are never visited)
-            bodies: List[bytes] = []
-            v = version
-            while True:
-                blob = self._chain.get(v)
-                if blob is None:
-                    blob, _ = self.store.read(v)
-                h, b = self._split_blob(blob)
-                bodies.append(b)
-                if h.get("base", True) or h.get("prev") is None:
-                    break
-                v = int(h["prev"])
-            bodies.reverse()
-            _, p_shapes, p_treedef = _flatten(self.params)
-            _, o_shapes, o_treedef = _flatten(self.opt_state)
-            state, flat = self.codec.decode_chain(
-                bodies, p_shapes, p_treedef, o_shapes, o_treedef
-            )
-            self._prev_flat = flat
-            self._last_label = version
-            self._since_base = 0  # force a fresh base on the next persist
-        else:
-            z = np.load(io.BytesIO(body))
-            leaves, treedef = jax.tree_util.tree_flatten(
-                (self.params, self.opt_state)
-            )
-            state = jax.tree_util.tree_unflatten(treedef, [z[k] for k in z.files])
-        self.params = self.opt_state = None  # free the replaced device state first
-        self.params, self.opt_state = jax.device_put(state)
+        with spans.span("trainer.decode"):
+            hdr, body = self._split_blob(payload)
+            del payload  # body is a copy: hold one, not two, of a 5 GB state
+            if self.codec is not None:
+                # walk explicit parent pointers down to a base (stale blobs
+                # from rolled-back label ranges are never visited)
+                bodies: List[bytes] = []
+                v = version
+                while True:
+                    blob = self._chain.get(v)
+                    if blob is None:
+                        blob, _ = self.store.read(v)
+                    h, b = self._split_blob(blob)
+                    bodies.append(b)
+                    if h.get("base", True) or h.get("prev") is None:
+                        break
+                    v = int(h["prev"])
+                bodies.reverse()
+                _, p_shapes, p_treedef = _flatten(self.params)
+                _, o_shapes, o_treedef = _flatten(self.opt_state)
+                state, flat = self.codec.decode_chain(
+                    bodies, p_shapes, p_treedef, o_shapes, o_treedef
+                )
+                self._prev_flat = flat
+                self._last_label = version
+                self._since_base = 0  # force a fresh base on the next persist
+            else:
+                z = np.load(io.BytesIO(body))
+                leaves, treedef = jax.tree_util.tree_flatten(
+                    (self.params, self.opt_state)
+                )
+                state = jax.tree_util.tree_unflatten(treedef, [z[k] for k in z.files])
+        with spans.span("trainer.upload"):
+            self.params = self.opt_state = None  # free the replaced device state first
+            self.params, self.opt_state = jax.device_put(state)
+            if spans.enabled():
+                jax.block_until_ready((self.params, self.opt_state))
         self.step = int(hdr["step"])
         self.loss_history = [tuple(r) for r in hdr["history"]]
         return meta

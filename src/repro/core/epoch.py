@@ -14,6 +14,7 @@ from __future__ import annotations
 import threading
 from typing import List
 
+from . import spans
 from .clock import Clock, REAL_CLOCK
 
 
@@ -29,7 +30,9 @@ class EpochRWLock:
     plain ``threading.Lock``.
     """
 
-    def __init__(self, clock: Clock = REAL_CLOCK) -> None:
+    def __init__(self, clock: Clock = REAL_CLOCK, so_id: str = "") -> None:
+        #: the StateObject whose actions this lock protects (span attrs)
+        self.so_id = so_id
         self._mutex = clock.lock()
         self._readers_cv = clock.condition(self._mutex)
         self._writer_cv = clock.condition(self._mutex)
@@ -57,7 +60,7 @@ class EpochRWLock:
                 self._stripe_counts[s] -= 1
             with self._mutex:
                 self._writer_cv.notify_all()
-        with self._mutex:
+        with spans.span("epoch.shared_wait", so_id=self.so_id), self._mutex:
             while self._writer_active or self._writers_waiting > 0:
                 self._readers_cv.wait()
             with self._stripe_locks[s]:

@@ -20,6 +20,7 @@ import zlib
 from dataclasses import dataclass, field
 from typing import Dict, FrozenSet, Iterable, List, Optional, Set, TYPE_CHECKING
 
+from . import spans
 from .clock import Clock, REAL_CLOCK
 from .ids import (
     DecisionIndex,
@@ -73,7 +74,7 @@ class DSERuntime:
         self.coordinator = config.coordinator
         self.clock = config.clock
 
-        self._epoch = EpochRWLock(self.clock)
+        self._epoch = EpochRWLock(self.clock, self.so_id)
         self._mu = self.clock.rlock()
         self._boundary_cond = self.clock.condition(self._mu)
 
@@ -129,6 +130,10 @@ class DSERuntime:
         filter applies here — a fresh incarnation has no boundary yet, and
         the disk it inherits is already the pruned suffix.
         """
+        with spans.span("connect", so_id=self.so_id) as sp:
+            self._connect(sp)
+
+    def _connect(self, sp) -> None:
         fragments, _, _ = self._list_fragments()
         resp = self.coordinator.connect(self.so_id, fragments)
         idx = DecisionIndex(resp.decisions)
@@ -149,7 +154,9 @@ class DSERuntime:
             # Stale blobs above the target (from versions a past decision
             # invalidated) stay on disk but are filtered everywhere by the
             # decision list, which the coordinator replays durably.
-            self.so.Restore(resp.restore_to)
+            sp.set(version=resp.restore_to)
+            with spans.span("restore", so_id=self.so_id, version=resp.restore_to):
+                self.so.Restore(resp.restore_to)
             valid = {
                 r.vertex.version for r in fragments if not idx.invalidates(r.vertex)
             }
@@ -333,44 +340,49 @@ class DSERuntime:
         With ``only_if_dirty`` it returns None and persists nothing when no
         action ran since the last persist began: dirtiness is re-checked
         under the exclusive epoch, because a concurrent persist may have
-        taken the state while this caller waited for the epoch."""
-        self._epoch.acquire_exclusive()
-        try:
-            with self._mu:
-                if only_if_dirty and not self._dirty:
-                    return None
-                label = self._v_cur if force_label is None else force_label
-                deps = frozenset(self._current_deps)
-                self._current_deps = set()
-                self._dep_log[label] = deps
-                self._labels.append(label)
-                self._v_cur = label + 1
-                self._dirty = False
-                self._last_persist = self.clock.now()
-                world = self.world
-            user_meta = b""
-            if self.config.user_metadata_fn is not None:
-                user_meta = self.config.user_metadata_fn()  # type: ignore[operator]
-            meta = encode_metadata(world, label, deps, user=user_meta)
-            done = self.clock.event()
-
-            def _callback() -> None:
+        taken the state while this caller waited for the epoch. The span
+        ``persist`` ends once the exclusive epoch is released."""
+        with spans.span("persist", so_id=self.so_id) as sp:
+            with spans.span("epoch.exclusive_wait", so_id=self.so_id):
+                self._epoch.acquire_exclusive()
+            try:
                 with self._mu:
-                    if label > self._committed:
-                        self._committed = label
-                    seq = self._report_seq
-                    self._report_seq += 1
-                    self._report_queue.append(
-                        PersistReport(
-                            Vertex(self.so_id, world, label), tuple(deps), seq=seq
-                        )
-                    )
-                done.set()
+                    if only_if_dirty and not self._dirty:
+                        return None
+                    label = self._v_cur if force_label is None else force_label
+                    deps = frozenset(self._current_deps)
+                    self._current_deps = set()
+                    self._dep_log[label] = deps
+                    self._labels.append(label)
+                    self._v_cur = label + 1
+                    self._dirty = False
+                    self._last_persist = self.clock.now()
+                    world = self.world
+                sp.set(version=label)
+                user_meta = b""
+                if self.config.user_metadata_fn is not None:
+                    user_meta = self.config.user_metadata_fn()  # type: ignore[operator]
+                meta = encode_metadata(world, label, deps, user=user_meta)
+                done = self.clock.event()
 
-            self.so.Persist(label, meta, _callback)
-        finally:
-            self._epoch.release_exclusive()
-        return label, done, world
+                def _callback() -> None:
+                    with self._mu:
+                        if label > self._committed:
+                            self._committed = label
+                        seq = self._report_seq
+                        self._report_seq += 1
+                        self._report_queue.append(
+                            PersistReport(
+                                Vertex(self.so_id, world, label), tuple(deps), seq=seq
+                            )
+                        )
+                    done.set()
+
+                with spans.span("persist.state", so_id=self.so_id, version=label):
+                    self.so.Persist(label, meta, _callback)
+            finally:
+                self._epoch.release_exclusive()
+            return label, done, world
 
     # ------------------------------------------------------------------ #
     # refresh: background protocol driving (paper Table 2)               #
@@ -547,7 +559,8 @@ class DSERuntime:
                 with self._mu:
                     floor = self._labels[0] if self._labels else 0
                 target = max(target, floor)
-                self.so.Restore(target)
+                with spans.span("restore", so_id=self.so_id, version=target):
+                    self.so.Restore(target)
                 with self._mu:
                     self.world = d.fsn
                     self._decisions.append(d)
@@ -572,6 +585,10 @@ class DSERuntime:
         boundary. Our own pending state is force-persisted once so local
         durability is never the reason a barrier waits a full group-commit
         period."""
+        with spans.span("barrier", so_id=self.so_id):
+            self._barrier(deps, timeout)
+
+    def _barrier(self, deps: FrozenSet[Vertex], timeout: Optional[float]) -> None:
         deadline = None if timeout is None else self.clock.now() + timeout
         with self._mu:
             needs_local = any(
@@ -587,8 +604,10 @@ class DSERuntime:
                 if all(self._boundary.get(dep.so_id, -1) >= dep.version for dep in deps):
                     return
             try:
-                self._flush_reports()
-                self._poll_coordinator()
+                with spans.span("report", so_id=self.so_id):
+                    self._flush_reports()
+                with spans.span("poll", so_id=self.so_id):
+                    self._poll_coordinator()
             except TimeoutError:
                 # Transient fabric failure (partition/loss): transport errors
                 # are retryable everywhere else; only the barrier's OWN

@@ -14,6 +14,8 @@ import threading
 from pathlib import Path
 from typing import Callable, Dict, List, Optional, Tuple, TYPE_CHECKING
 
+from . import spans
+
 if TYPE_CHECKING:  # pragma: no cover
     from .runtime import DSEConfig, DSERuntime
     from .sthread import SThread
@@ -173,6 +175,11 @@ class VersionStore:
 
     def write(self, version: int, payload: bytes, metadata: bytes) -> None:
         """Durably write one version (synchronous; callers wrap in executor)."""
+        with spans.span("store.write", store=self.root.name, version=version,
+                        bytes=len(payload)):
+            self._write(version, payload, metadata)
+
+    def _write(self, version: int, payload: bytes, metadata: bytes) -> None:
         if self._poisoned:
             raise RuntimeError("VersionStore poisoned (incarnation crashed)")
         if self._simulate_io_ms > 0:
@@ -222,6 +229,12 @@ class VersionStore:
 
     # -- read path ------------------------------------------------------
     def read(self, version: int) -> Tuple[bytes, bytes]:
+        with spans.span("store.read", store=self.root.name, version=version) as sp:
+            payload, metadata = self._read(version)
+            sp.set(bytes=len(payload))
+        return payload, metadata
+
+    def _read(self, version: int) -> Tuple[bytes, bytes]:
         with self._lock:
             if version in self._mem:
                 return self._mem[version]

@@ -17,7 +17,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from ..core import LocalCluster, StateObject, VersionStore
+from ..core import LocalCluster, StateObject, VersionStore, spans
 from ..models import cache_descs, decode_step, forward
 from ..models.config import ModelConfig
 from ..models.params import is_desc
@@ -92,6 +92,7 @@ class DecodeSessionStateObject(StateObject):
     def generate(self, n: int) -> Optional[List[int]]:
         """Speculatively decode ``n`` tokens (one action per token)."""
         out = []
+        so_id = self.runtime.so_id
         for _ in range(n):
             if not self.StartAction(None):
                 return None
@@ -100,11 +101,13 @@ class DecodeSessionStateObject(StateObject):
                 self.EndAction()
                 break
             prev = self.tokens[-1] if self.tokens else 0
-            logits, self._cache = self._step(
-                self.params, self._cache,
-                jnp.asarray([[prev]], jnp.int32), jnp.asarray(idx, jnp.int32),
-            )
-            t = int(jnp.argmax(logits[0, 0, : self.cfg.vocab_size]))
+            with spans.span("session.dispatch", so_id=so_id):
+                logits, self._cache = self._step(
+                    self.params, self._cache,
+                    jnp.asarray([[prev]], jnp.int32), jnp.asarray(idx, jnp.int32),
+                )
+            with spans.span("session.sync", so_id=so_id):
+                t = int(jnp.argmax(logits[0, 0, : self.cfg.vocab_size]))
             self.tokens.append(t)
             out.append(t)
             self.EndAction()
