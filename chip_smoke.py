@@ -54,9 +54,10 @@ STEPS, KILL_AT = 3, 3
 FREE_GROUP_COMMIT_S = 3600.0
 # The killed run commits as soon as state is dirty, saving the trainer after
 # every step, so which save is durable at the kill follows from the step
-# count, not from wall time: the save after step 0 is written while the save
-# after step 1 is being snapshotted (a 5 GB save is written faster than it
-# is snapshotted), so killing after step 2 resumes from step 1.
+# count, not from wall time: a save waits for the previous save's write to
+# end before it copies the state, so the save after step 0 is durable before
+# the save after step 1 is taken, and killing after step 2 resumes from
+# step 1.
 KILLED_GROUP_COMMIT_S = 0.0
 SERVE_TOKENS, SERVE_KILL_AT = 16, 8
 RUN_DIR = ROOT / ".chip_smoke"

@@ -6,7 +6,8 @@ trainer snapshot. The recorder is off by default, and then ``span`` returns
 one shared no-op context: a flag check, with no clock read and no append.
 ``enable()`` turns it on. Each span then records its ``perf_counter_ns``
 start and end, its thread, its id, its parent (the innermost span open on
-the same thread when it began) and its attrs into a bounded buffer that
+the same thread when it began, or the span it was handed off from: see
+``current``) and its attrs into a bounded buffer that
 ``records()`` returns; a full buffer counts what it drops. Where JAX is
 already imported, the span also opens ``jax.profiler.TraceAnnotation``
 named ``dse.<name>`` with the same attrs, so a profiler trace shows it on
@@ -76,10 +77,14 @@ class Recorder:
         self._ids = itertools.count(1)
         self._local = threading.local()
 
-    def span(self, name: str, **attrs):
+    def span(self, name: str, *, parent: int = 0, **attrs):
         if not self.on:
             return _NOOP
-        return _Span(self, name, attrs)
+        return _Span(self, name, attrs, parent)
+
+    def current(self) -> int:
+        stack = self._stack()
+        return stack[-1] if self.on and stack else 0
 
     def records(self) -> List[Record]:
         with self._lock:
@@ -107,14 +112,17 @@ class Recorder:
 class _Span:
     __slots__ = ("_rec", "name", "attrs", "_id", "_parent", "_start", "_ann")
 
-    def __init__(self, rec: Recorder, name: str, attrs: Dict[str, object]) -> None:
+    def __init__(self, rec: Recorder, name: str, attrs: Dict[str, object],
+                 parent: int = 0) -> None:
         self._rec = rec
         self.name = name
         self.attrs = attrs
+        self._parent = parent
 
     def __enter__(self) -> "_Span":
         stack = self._rec._stack()
-        self._parent = stack[-1] if stack else 0
+        if not self._parent and stack:
+            self._parent = stack[-1]
         self._id = next(self._rec._ids)
         stack.append(self._id)
         profiler = sys.modules.get("jax.profiler")
@@ -143,11 +151,21 @@ class _Span:
 RECORDER = Recorder()
 
 
-def span(name: str, **attrs):
-    """A span named ``name``; the shared no-op context while off."""
+def span(name: str, *, parent: int = 0, **attrs):
+    """A span named ``name``; the shared no-op context while off. A
+    ``parent`` (an id from ``current``) stands in for the innermost span open
+    on this thread: the span is work handed off from a span on another
+    thread."""
     if not RECORDER.on:
         return _NOOP
-    return _Span(RECORDER, name, attrs)
+    return _Span(RECORDER, name, attrs, parent)
+
+
+def current() -> int:
+    """The id of the innermost span open on this thread, to hand off to
+    work another thread does for it; 0 where none is, or the recorder is
+    off."""
+    return RECORDER.current()
 
 
 def enable() -> None:

@@ -173,13 +173,20 @@ class VersionStore:
         incarnation must not keep mutating durable state, paper §5.1)."""
         self._poisoned = True
 
-    def write(self, version: int, payload: bytes, metadata: bytes) -> None:
-        """Durably write one version (synchronous; callers wrap in executor)."""
+    def write(self, version: int, payload: bytes, metadata: bytes,
+              ready: Optional[Callable[[int], bool]] = None) -> None:
+        """Durably write one version (synchronous; callers wrap in executor).
+
+        ``ready``, for a payload still being filled while it is written,
+        blocks until the payload's first ``n`` bytes are final
+        (``ready(n)``), and returns False to abandon the write: nothing is
+        then published."""
         with spans.span("store.write", store=self.root.name, version=version,
                         bytes=len(payload)):
-            self._write(version, payload, metadata)
+            self._write(version, payload, metadata, ready)
 
-    def _write(self, version: int, payload: bytes, metadata: bytes) -> None:
+    def _write(self, version: int, payload: bytes, metadata: bytes,
+               ready: Optional[Callable[[int], bool]] = None) -> None:
         if self._poisoned:
             raise RuntimeError("VersionStore poisoned (incarnation crashed)")
         if self._simulate_io_ms > 0:
@@ -193,17 +200,23 @@ class VersionStore:
         # temp file, never the one its replacement writes for the same label
         tmp = self.root / f".v{version}.{id(self):x}.tmp"
         final = self.root / f"v{version}.blob"
+        whole = True
         with open(tmp, "wb") as f:
             f.write(len(metadata).to_bytes(8, "little"))
             f.write(metadata)
             view = memoryview(payload)
             for off in range(0, len(view), _WRITE_CHUNK):
-                if self._poisoned:
-                    break  # a crashed incarnation stops writing mid-version
-                f.write(view[off : off + _WRITE_CHUNK])
-            f.flush()
-            os.fsync(f.fileno())
-        if self._poisoned:
+                end = min(off + _WRITE_CHUNK, len(view))
+                if self._poisoned or (ready is not None and not ready(end)):
+                    # a crashed incarnation, or a payload whose fill
+                    # stopped, stops writing mid-version
+                    whole = False
+                    break
+                f.write(view[off:end])
+            if whole:
+                f.flush()
+                os.fsync(f.fileno())
+        if self._poisoned or not whole:
             # crashed incarnation must not PUBLISH: an in-flight write that
             # survived the entry check could otherwise clobber the restarted
             # incarnation's same-numbered version with rolled-back state.
@@ -211,7 +224,8 @@ class VersionStore:
                 os.unlink(tmp)
             except OSError:
                 pass
-            raise RuntimeError("VersionStore poisoned (incarnation crashed)")
+            raise RuntimeError("VersionStore poisoned (incarnation crashed)"
+                               if self._poisoned else "payload abandoned while being written")
         os.replace(tmp, final)
         with self._lock:
             self._mem[version] = (payload, metadata)

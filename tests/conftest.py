@@ -77,3 +77,11 @@ def settle(
             return True
         clock.sleep(interval)
     return predicate()
+
+
+def drain_io(timeout: float = 60.0) -> None:
+    """Join every background Persist thread (``spawn_io`` names them
+    ``...persist-io``), so a test sees its saves' writes ended."""
+    for t in threading.enumerate():
+        if t.name.endswith("persist-io"):
+            t.join(timeout)

@@ -7,12 +7,14 @@ from __future__ import annotations
 import glob
 import io
 import threading
+import time
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from conftest import drain_io
 from repro.core import spans
 
 
@@ -68,6 +70,28 @@ def test_spans_nest_per_thread(recording):
     assert o.attrs == {"so_id": "m", "version": 7}
     assert o.start_ns <= i.start_ns <= i.end_ns <= o.end_ns
     assert len({o.id, i.id, x.id}) == 3
+
+
+def test_a_handed_off_span_is_the_child_of_the_span_it_left(recording):
+    assert spans.current() == 0
+
+    def behind(handed_off):
+        with spans.span("behind", parent=handed_off):
+            with spans.span("part"):
+                pass
+
+    with spans.span("outer"):
+        here = spans.current()
+        t = threading.Thread(target=behind, args=(here,))
+        t.start()
+        t.join(5)
+    recs = spans.records()
+    (o,), (b,), (part,) = by_name(recs, "outer"), by_name(recs, "behind"), by_name(recs, "part")
+    assert o.id == here and b.parent == o.id and b.thread != o.thread
+    assert part.parent == b.id and b.attrs == {}
+    spans.disable()
+    with spans.span("off"):
+        assert spans.current() == 0
 
 
 def test_a_full_buffer_counts_its_drops():
@@ -146,25 +170,60 @@ def test_persist_restore_round_trip_through_the_archive(tmp_path):
         np.testing.assert_array_equal(z[k], b)
 
 
-def test_a_trainer_save_has_a_fetch_and_an_encode_per_leaf(recording, tmp_path):
-    from repro.checkpoint import TrainerStateObject
+def test_a_trainer_save_has_a_fetch_and_an_encode_per_leaf(recording, tmp_path, monkeypatch):
+    """Under the save's ``persist.state``: its one ``trainer.fetch``, after a
+    ``trainer.save_wait`` where the previous save's write-behind still ran;
+    handed off from it, on an IO thread, ``trainer.write_behind`` with the
+    store's write and, handed off again to the thread that fills the
+    archive, one ``trainer.encode`` per leaf and the ``trainer.join``."""
+    from repro.checkpoint import TrainerStateObject, archive
     from repro.core import LocalCluster
+
+    gate = threading.Event()
+    fill = archive.Archive.fill
+
+    def held(*args):
+        assert gate.wait(60)
+        return fill(*args)
+
+    monkeypatch.setattr(archive.Archive, "fill", held)
 
     def step(p, o, batch):
         return jax.tree_util.tree_map(lambda x: x + 1, p), o, jnp.float32(0.5)
 
+    def exclusive_waits():
+        return len([r for r in by_name(spans.records(), "epoch.exclusive_wait")
+                    if r.attrs["so_id"] == "trainer"])
+
     cluster = LocalCluster(tmp_path, refresh_interval=None, group_commit_interval=3600)
+    gate.set()  # the connect-time save runs through
     try:
         trainer = cluster.add("trainer", lambda: TrainerStateObject(
             tmp_path / "trainer", _state, step))
+        drain_io()
+        gate.clear()
+        labels = []
         assert trainer.train_on(0, np.zeros((1, 4), np.int32)) is not None
-        label = trainer.runtime.persist_if_dirty()
+        labels.append(trainer.runtime.persist_if_dirty())
+        assert trainer.train_on(1, np.zeros((1, 4), np.int32)) is not None
+        waits = exclusive_waits()
+        second = threading.Thread(
+            target=lambda: labels.append(trainer.runtime.persist_if_dirty()))
+        second.start()
+        deadline = time.monotonic() + 60
+        while exclusive_waits() == waits:  # until the second save holds the epoch
+            assert time.monotonic() < deadline
+            time.sleep(0.005)
+        time.sleep(0.1)
+        gate.set()
+        second.join(60)
+        assert not second.is_alive()
+        drain_io()
     finally:
+        gate.set()
         cluster.shutdown()
-    assert label == 1
+    assert labels == [1, 2]
     recs = spans.records()
-    (save,) = [r for r in by_name(recs, "persist")
-               if r.attrs == {"so_id": "trainer", "version": 1}]
     children = {}
     for r in recs:
         children.setdefault(r.parent, []).append(r)
@@ -174,11 +233,24 @@ def test_a_trainer_save_has_a_fetch_and_an_encode_per_leaf(recording, tmp_path):
             yield c
             yield from below(c)
 
-    inside = list(below(save))
     n = len(jax.tree_util.tree_leaves(_state()))
-    for name in ("trainer.fetch", "trainer.encode"):
-        assert sorted(r.attrs["leaf"] for r in by_name(inside, name)) == list(range(n))
-    assert len(by_name(inside, "trainer.join")) == 1
-    assert [r.name for r in children[save.id]] == ["epoch.exclusive_wait", "persist.state"]
+    for version, first in ((1, ["trainer.fetch"]), (2, ["trainer.save_wait", "trainer.fetch"])):
+        (save,) = [r for r in by_name(recs, "persist")
+                   if r.attrs == {"so_id": "trainer", "version": version}]
+        assert [r.name for r in children[save.id]] == ["epoch.exclusive_wait", "persist.state"]
+        state = children[save.id][1]
+        assert [r.name for r in children[state.id]] == first + ["trainer.write_behind"]
+        (behind,) = by_name(children[state.id], "trainer.write_behind")
+        assert behind.thread != save.thread and behind.attrs == {"version": version}
+        assert behind.start_ns >= state.start_ns
+        inside = list(below(behind))
+        assert sorted(r.attrs["leaf"] for r in by_name(inside, "trainer.encode")) == list(range(n))
+        assert len(by_name(inside, "trainer.join")) == 1
+        (write,) = by_name(inside, "store.write")
+        assert write.attrs["version"] == version and write.thread == behind.thread
+        fills = {r.thread for r in inside if r.name in ("trainer.encode", "trainer.join")}
+        # the fill runs while the write waits on it; a thread's ident is
+        # reused once it has ended, so only these two are told apart
+        assert len(fills) == 1 and behind.thread not in fills
     (init,) = by_name(recs, "trainer.init")
     assert init.end_ns <= save.start_ns
