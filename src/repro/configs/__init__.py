@@ -1,6 +1,8 @@
 """Assigned-architecture registry: one module per architecture, each with
 ``config()`` (exact published dims) and ``smoke_config()`` (reduced,
-same family structure, CPU-runnable)."""
+same family structure, CPU-runnable). A configuration cut to fit one chip
+has a module of its own beside the published one (``zamba2_7b_l12``),
+registered by alias and left out of ``ARCHITECTURES``."""
 from __future__ import annotations
 
 import importlib
@@ -15,6 +17,7 @@ ARCHITECTURES: List[str] = [
     "glm4_9b",
     "gemma3_4b",
     "zamba2_1p2b",
+    "zamba2_7b",
     "granite_moe_3b_a800m",
     "deepseek_v2_lite_16b",
     "mamba2_370m",
@@ -28,6 +31,8 @@ _ALIASES = {
     "glm4-9b": "glm4_9b",
     "gemma3-4b": "gemma3_4b",
     "zamba2-1.2b": "zamba2_1p2b",
+    "zamba2-7b": "zamba2_7b",
+    "zamba2-7b-l12": "zamba2_7b_l12",
     "granite-moe-3b-a800m": "granite_moe_3b_a800m",
     "deepseek-v2-lite-16b": "deepseek_v2_lite_16b",
     "mamba2-370m": "mamba2_370m",

@@ -184,6 +184,11 @@ class DSERuntime:
         if self._dead:
             raise CrashedError(f"{self.so_id}: this incarnation has crashed")
 
+    def exclusive_epoch(self):
+        """The epoch's exclusive side, as a context: no action of this
+        incarnation runs while it is held."""
+        return self._epoch.exclusive()
+
     # ------------------------------------------------------------------ #
     # header classification (instrumentation + partition rules)          #
     # ------------------------------------------------------------------ #

@@ -84,11 +84,13 @@ def scaled_pair(cfg):
         large = dc.replace(cfg, num_layers=fk + 4)
         return small, large, (cfg.num_layers - fk - 2) // 2
     if cfg.family == "hybrid":
-        p = cfg.hybrid_attn_period
-        tail = cfg.num_layers % p
-        small = dc.replace(cfg, num_layers=p + tail)
-        large = dc.replace(cfg, num_layers=2 * p + tail)
-        return small, large, (cfg.num_layers - (p + tail)) // p
+        # the unit is a site and the Mamba layers up to the next one; its
+        # length varies by a layer or two along zamba2's stack, so this
+        # extrapolation is approximate there
+        ids = cfg.hybrid_layer_ids
+        small = dc.replace(cfg, num_layers=ids[1], hybrid_layer_ids=ids[:1])
+        large = dc.replace(cfg, num_layers=ids[2], hybrid_layer_ids=ids[:2])
+        return small, large, len(ids) - 1
     if cfg.family == "vlm":
         p = cfg.cross_attn_period
         small = dc.replace(cfg, num_layers=p)

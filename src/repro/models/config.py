@@ -72,8 +72,13 @@ class ModelConfig:
     moe: Optional[MoEConfig] = None
     mla: Optional[MLAConfig] = None
     ssm: Optional[SSMConfig] = None
-    # hybrid (zamba2): one shared attention block applied every N SSM layers
-    hybrid_attn_period: int = 0
+    # hybrid (zamba2): every layer is a Mamba-2 layer; before the layers
+    # named in hybrid_layer_ids a shared attention+MLP block runs over
+    # concat(h, embedding), site k using block k % num_mem_blocks, with the
+    # site's own LoRA on the MLP's gate/up (rank adapter_rank) and linear
+    hybrid_layer_ids: Tuple[int, ...] = ()
+    num_mem_blocks: int = 0
+    adapter_rank: int = 0
     # encoder-decoder (seamless): encoder depth; num_layers = decoder depth
     encoder_layers: int = 0
     source_len: int = 1024           # stubbed modality frontend: frame count
@@ -124,7 +129,7 @@ class ModelConfig:
             total += self.vocab_padded * d
         total += d  # final norm
 
-        def attn_params() -> int:
+        def attn_params(d_in: int = d) -> int:
             if self.mla is not None:
                 m = self.mla
                 qk_dim = m.qk_nope_head_dim + m.qk_rope_head_dim
@@ -135,7 +140,7 @@ class ModelConfig:
                 p += m.kv_lora_rank * n_q * (m.qk_nope_head_dim + m.v_head_dim)
                 p += n_q * m.v_head_dim * d
                 return p
-            return d * n_q * hd + 2 * d * n_kv * hd + n_q * hd * d
+            return d_in * n_q * hd + 2 * d_in * n_kv * hd + n_q * hd * d
 
         def mlp_params(ff: int) -> int:
             return 3 * d * ff  # gated MLP
@@ -166,10 +171,11 @@ class ModelConfig:
                         total += mo.num_shared * 3 * d * mo.d_expert
                 else:
                     total += mlp_params(self.d_ff)
-        if self.family in ("ssm",):
-            pass
-        if self.hybrid_attn_period:
-            total += attn_params() + mlp_params(self.d_ff) + 2 * d  # shared block
+        if self.hybrid_layer_ids:
+            a = 2 * d  # the shared block reads concat(h, embedding)
+            block = a + attn_params(a) + d + mlp_params(self.d_ff)  # norms, attn, MLP
+            site = d * self.adapter_rank + 2 * self.adapter_rank * self.d_ff + d * d
+            total += self.num_mem_blocks * block + len(self.hybrid_layer_ids) * site
         if self.encoder_layers:
             total += self.encoder_layers * (2 * d + attn_params() + mlp_params(self.d_ff))
             total += self.num_layers * (d + attn_params())  # decoder cross-attn

@@ -54,13 +54,16 @@ def _soft_cap(logits: jax.Array, cap: float) -> jax.Array:
 # --------------------------------------------------------------------------- #
 # attention                                                                    #
 # --------------------------------------------------------------------------- #
-def attn_descs(cfg: ModelConfig, cross: bool = False) -> Dict[str, PDesc]:
+def attn_descs(cfg: ModelConfig, cross: bool = False, d_in: Optional[int] = None) -> Dict[str, PDesc]:
+    """Projections from an input of width ``d_in`` (default d_model) to the
+    heads, and from the heads back to d_model."""
     d, hd = cfg.d_model, cfg.resolved_head_dim
+    a = d_in or d
     nq, nkv = cfg.num_heads, cfg.num_kv_heads
     descs = {
-        "wq": PDesc((d, nq, hd), ("embed", "heads", None)),
-        "wk": PDesc((d, nkv, hd), ("embed", "kv_heads", None)),
-        "wv": PDesc((d, nkv, hd), ("embed", "kv_heads", None)),
+        "wq": PDesc((a, nq, hd), ("embed", "heads", None)),
+        "wk": PDesc((a, nkv, hd), ("embed", "kv_heads", None)),
+        "wv": PDesc((a, nkv, hd), ("embed", "kv_heads", None)),
         "wo": PDesc((nq, hd, d), ("heads", None, "embed")),
     }
     if cross:
@@ -74,12 +77,12 @@ def _sdpa(
     v: jax.Array,        # (B, T, N, H)
     mask: Optional[jax.Array],  # broadcastable to (B, N, S, T) or None
     softcap: float,
+    scale: float,
 ) -> jax.Array:
     # NOTE (sharding): GQA is computed in repeat-kv form on purpose — a
     # (kv_heads, groups) split of the head dim is unshardable whenever
     # kv_heads < |model| (GSPMD would replicate the S x T logits). Repeating
     # K/V keeps every attention tensor sharded on the full head dim.
-    scale = 1.0 / np.sqrt(q.shape[-1])
     logits = jnp.einsum("bsnh,btnh->bnst", q, k).astype(F32) * scale
     logits = _soft_cap(logits, softcap)
     if mask is not None:
@@ -100,10 +103,13 @@ def attention(
     ring: bool = False,                 # cache is a ring buffer of size window
     cross_src: Optional[jax.Array] = None,         # (B, Ssrc, D) encoder/image
     causal: bool = True,
+    scale: Optional[float] = None,      # score scale (default head_dim ** -0.5)
 ) -> Tuple[jax.Array, Optional[Dict[str, jax.Array]]]:
     B, S, D = x.shape
     nq, nkv, hd = cfg.num_heads, cfg.num_kv_heads, cfg.resolved_head_dim
     groups = nq // nkv
+    if scale is None:
+        scale = 1.0 / np.sqrt(hd)
 
     q = jnp.einsum("bsd,dnh->bsnh", x, p["wq"])
     kv_in = cross_src if cross_src is not None else x
@@ -170,7 +176,6 @@ def attention(
         # contracts locally per T-shard and GSPMD inserts only small
         # softmax-stat / partial-sum all-reduces.
         qg = q.reshape(B, S, nkv, groups, hd)
-        scale = 1.0 / np.sqrt(hd)
         logits = jnp.einsum("bsngh,btnh->bngst", qg, k).astype(F32) * scale
         logits = _soft_cap(logits, cfg.logit_softcap)
         if mask is not None:
@@ -181,7 +186,7 @@ def attention(
         if groups > 1:
             k = jnp.repeat(k, groups, axis=2)
             v = jnp.repeat(v, groups, axis=2)
-        out = _sdpa(q, k, v, mask, cfg.logit_softcap)
+        out = _sdpa(q, k, v, mask, cfg.logit_softcap, scale)
     out = jnp.einsum("bsnh,nhd->bsd", out, p["wo"])
     return out, new_cache
 
@@ -276,12 +281,24 @@ def mlp_descs(cfg: ModelConfig, d_ff: Optional[int] = None) -> Dict[str, PDesc]:
     }
 
 
-def mlp(p: Dict[str, jax.Array], x: jax.Array, activation: str) -> jax.Array:
-    act = jax.nn.gelu if activation == "gelu" else jax.nn.silu
-    h = act(jnp.einsum("bsd,df->bsf", x, p["wi_gate"])) * jnp.einsum(
-        "bsd,df->bsf", x, p["wi_up"]
-    )
-    return jnp.einsum("bsf,fd->bsd", h, p["wo"])
+_ACTIVATIONS = {
+    "silu": jax.nn.silu,
+    "gelu": jax.nn.gelu,  # tanh approximation (gemma)
+    "gelu_exact": lambda x: jax.nn.gelu(x, approximate=False),  # erf form (zamba2)
+}
+
+
+def mlp(p: Dict[str, jax.Array], x: jax.Array, activation: str,
+        adapter: Optional[Dict[str, jax.Array]] = None) -> jax.Array:
+    """Gated MLP; ``adapter`` ({"lora_a": (d, r), "lora_gate", "lora_up":
+    (r, f)}) adds a low-rank term to the gate and up projections."""
+    gate = jnp.einsum("bsd,df->bsf", x, p["wi_gate"])
+    up = jnp.einsum("bsd,df->bsf", x, p["wi_up"])
+    if adapter is not None:
+        low = jnp.einsum("bsd,dr->bsr", x, adapter["lora_a"])
+        gate = gate + jnp.einsum("bsr,rf->bsf", low, adapter["lora_gate"])
+        up = up + jnp.einsum("bsr,rf->bsf", low, adapter["lora_up"])
+    return jnp.einsum("bsf,fd->bsd", _ACTIVATIONS[activation](gate) * up, p["wo"])
 
 
 # --------------------------------------------------------------------------- #

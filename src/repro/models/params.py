@@ -46,13 +46,30 @@ def stack_tree(tree, n: int):
 # --------------------------------------------------------------------------- #
 # materialization                                                              #
 # --------------------------------------------------------------------------- #
+_HEAD_AXES = ("heads", "kv_heads")
+
+
+def fan_in(desc: PDesc) -> int:
+    """The size of the dimensions a weight contracts with its input: the
+    second-to-last dimension of a matrix; of an attention projection, the
+    input width of ``(in, heads, head_dim)`` and ``heads * head_dim`` of
+    ``(heads, head_dim, out)``."""
+    shape, axes = desc.shape, desc.axes
+    if len(shape) < 2:
+        return shape[-1]
+    if len(shape) >= 3 and axes[-2] in _HEAD_AXES:
+        return shape[-3]
+    if len(shape) >= 3 and axes[-3] in _HEAD_AXES:
+        return shape[-3] * shape[-2]
+    return shape[-2]
+
+
 def _init_leaf(desc: PDesc, key: jax.Array, dtype) -> jax.Array:
     if desc.init == "zeros":
         return jnp.zeros(desc.shape, dtype)
     if desc.init == "ones":
         return jnp.ones(desc.shape, dtype)
-    fan_in = desc.shape[-2] if len(desc.shape) >= 2 else desc.shape[-1]
-    std = desc.scale / np.sqrt(max(fan_in, 1))
+    std = desc.scale / np.sqrt(max(fan_in(desc), 1))
     if desc.init == "small":
         std = 0.01 * desc.scale
     return (jax.random.normal(key, desc.shape, jnp.float32) * std).astype(dtype)

@@ -202,9 +202,17 @@ def mamba2_mixer(
     y = y + xs * p["D"].astype(x.dtype)[None, None, :, None]
     y = y.reshape(B, S, di)
     # gated RMSNorm then down-projection (Mamba-2 block epilogue)
-    y = y * jax.nn.silu(z)
-    var = jnp.mean(jnp.square(y.astype(F32)), axis=-1, keepdims=True)
-    y = (y.astype(F32) * jax.lax.rsqrt(var + cfg.norm_eps)).astype(x.dtype) * (
-        1.0 + p["norm_w"].astype(x.dtype)
-    )
+    y = gated_rms_norm(y, z, p["norm_w"], s.n_groups, cfg.norm_eps)
     return jnp.einsum("bsi,id->bsd", y, p["out_proj"]), new_cache
+
+
+def gated_rms_norm(y: jax.Array, z: jax.Array, w: jax.Array, groups: int, eps: float) -> jax.Array:
+    """RMSNorm of y * silu(z) over each of ``groups`` equal slices of the
+    last axis apart (Zamba2RMSNormGated, group_size = d_inner / ngroups),
+    scaled by 1 + w."""
+    y = y * jax.nn.silu(z)
+    shape = y.shape
+    g = y.reshape(shape[:-1] + (groups, shape[-1] // groups))
+    var = jnp.mean(jnp.square(g.astype(F32)), axis=-1, keepdims=True)
+    g = (g.astype(F32) * jax.lax.rsqrt(var + eps)).astype(y.dtype)
+    return g.reshape(shape) * (1.0 + w.astype(y.dtype))

@@ -4,22 +4,22 @@ Every architecture is described by the same ``ModelConfig``; this module
 builds (a) the parameter-descriptor tree, (b) ``forward`` for train/prefill,
 (c) ``decode_step`` against explicit caches, and (d) the LM loss. Stacks use
 ``lax.scan`` over layer-stacked parameters; heterogeneous stacks (gemma3
-local:global, zamba2 shared-attention, vision cross-attention) scan over
-*groups* whose inner structure is homogeneous, so the HLO stays compact at
-any depth.
+local:global, vision cross-attention) scan over *groups* whose inner
+structure is homogeneous, so the HLO stays compact at any depth; zamba2
+scans each run of Mamba layers between its shared-block sites.
 
 Family map:
   dense / moe    -> forward_dense     (MLA and MoE are per-block options)
   gemma3         -> grouped local/global stack (ring caches for local layers)
   ssm            -> forward_ssm       (Mamba-2 SSD)
-  hybrid         -> forward_hybrid    (zamba2: shared attn block every N SSM layers)
+  hybrid         -> forward_hybrid    (zamba2: shared attn+MLP blocks at listed sites)
   encdec         -> forward_encdec    (seamless: stub audio frames -> encoder)
   vlm            -> forward_vlm       (llama-3.2-vision: gated cross-attn groups)
 """
 from __future__ import annotations
 
 import functools
-from typing import Any, Callable, Dict, Optional, Tuple
+from typing import Any, Callable, Dict, List, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -373,15 +373,41 @@ def ssm_descs_tree(cfg: ModelConfig) -> Dict:
     return descs
 
 
+def hybrid_plan(cfg: ModelConfig) -> List[Tuple[Optional[int], int]]:
+    """The hybrid stack as segments of Mamba layers: (site, layers), where
+    ``site`` is the index into ``hybrid_layer_ids`` of the shared-block site
+    that runs before the segment's first layer (None for the layers before
+    the first site)."""
+    ids = list(cfg.hybrid_layer_ids)
+    assert ids == sorted(set(ids)) and 0 <= ids[0] and ids[-1] < cfg.num_layers, ids
+    bounds = ids + [cfg.num_layers]
+    plan: List[Tuple[Optional[int], int]] = [(None, ids[0])] if ids[0] else []
+    return plan + [(k, bounds[k + 1] - bounds[k]) for k in range(len(ids))]
+
+
 def hybrid_descs(cfg: ModelConfig) -> Dict:
-    p = cfg.hybrid_attn_period
-    n_groups = cfg.num_layers // p
-    tail = cfg.num_layers - n_groups * p
+    """Zamba2: the Mamba layers as one stack per segment of ``hybrid_plan``
+    (a scan over a slice of one stack would copy the slice), the shared
+    blocks stacked once each, and every site's adapter and linear stacked
+    in site order."""
+    d, r = cfg.d_model, cfg.adapter_rank
+    a = 2 * d  # the shared block reads concat(h, embedding)
     descs = _embed_descs(cfg)
-    descs["shared_attn"] = _block_descs(cfg, kind="attn")  # ONE shared block
-    descs["group_ssm"] = stack_tree(stack_tree(_block_descs(cfg, kind="ssm"), p), n_groups)
-    if tail:
-        descs["tail_ssm"] = stack_tree(_block_descs(cfg, kind="ssm"), tail)
+    block = {
+        "ln_in": PDesc((a,), ("embed",), init="zeros"),
+        "attn": attn_descs(cfg, d_in=a),
+        "ln_ff": PDesc((d,), ("embed",), init="zeros"),
+        "mlp": mlp_descs(cfg),
+    }
+    site = {
+        "lora_a": PDesc((d, r), ("embed", None)),
+        "lora_gate": PDesc((r, cfg.d_ff), (None, "ffn")),
+        "lora_up": PDesc((r, cfg.d_ff), (None, "ffn")),
+        "linear": PDesc((d, d), ("embed", None)),
+    }
+    descs["blocks"] = stack_tree(block, cfg.num_mem_blocks)
+    descs["sites"] = stack_tree(site, len(cfg.hybrid_layer_ids))
+    descs["mamba"] = [stack_tree(_block_descs(cfg, kind="ssm"), n) for _, n in hybrid_plan(cfg)]
     return descs
 
 
@@ -411,6 +437,57 @@ def forward_ssm(
     return _logits(cfg, params, x, last_only), ({"layers": new_cache} if decode else None), aux
 
 
+def _shared_block(
+    cfg: ModelConfig,
+    params: Dict,
+    site: int,
+    h: jax.Array,
+    emb: jax.Array,
+    positions: jax.Array,
+    cache: Optional[Dict],
+    cache_index: Optional[jax.Array],
+) -> Tuple[jax.Array, Optional[Dict]]:
+    """Zamba2's shared block at one site (``Zamba2HybridLayer`` up to its
+    Mamba layer): RMSNorm of concat(h, embedding), attention scaled by
+    (head_dim / 2) ** -0.5, RMSNorm, the gated MLP with the site's LoRA on
+    gate and up, no residual; then the site's linear."""
+    take = lambda tree, i: jax.tree_util.tree_map(lambda w: w[i], tree)
+    bp = take(params["blocks"], site % cfg.num_mem_blocks)
+    sp = take(params["sites"], site)
+    with jax.named_scope("zamba2.shared_block"):
+        t = rms_norm(jnp.concatenate([h, emb], axis=-1), bp["ln_in"], cfg.norm_eps)
+        t, new_cache = attention(
+            bp["attn"], t, cfg, positions, cache=cache, cache_index=cache_index,
+            scale=(cfg.resolved_head_dim / 2) ** -0.5,
+        )
+        t = mlp(bp["mlp"], rms_norm(t, bp["ln_ff"], cfg.norm_eps), cfg.activation, adapter=sp)
+    with jax.named_scope("zamba2.site_linear"):
+        t = jnp.einsum("bsd,de->bse", t, sp["linear"])
+    return t, new_cache
+
+
+def _mamba_segment(
+    cfg: ModelConfig,
+    stacked: Dict,
+    x: jax.Array,
+    t: Optional[jax.Array],
+    cache: Optional[Dict],
+    remat: str,
+) -> Tuple[jax.Array, Optional[Dict]]:
+    """Zamba2 Mamba layers, h + mixer(norm(h + t)), with the site's output
+    ``t`` added to the first layer's input only."""
+    def body(carry, xs):
+        h, t = carry
+        lp, c = xs
+        hin = h if t is None else h + t
+        out, new_c = mamba2_mixer(lp["mixer"], rms_norm(hin, lp["ln1"], cfg.norm_eps), cfg, cache=c)
+        return (h + out, None if t is None else jnp.zeros_like(t)), new_c
+
+    with jax.named_scope("zamba2.mamba"):
+        (x, _), new_cache = _scan(_maybe_remat(body, remat), (x, t), (stacked, cache))
+    return x, new_cache
+
+
 def forward_hybrid(
     cfg: ModelConfig,
     params: Dict,
@@ -421,9 +498,8 @@ def forward_hybrid(
     cache: Optional[Dict] = None,
     cache_index: Optional[jax.Array] = None,
 ) -> Tuple[jax.Array, Optional[Dict], jax.Array]:
-    p = cfg.hybrid_attn_period
-    n_groups = cfg.num_layers // p
-    tail = cfg.num_layers - n_groups * p
+    """Zamba2 (``Zamba2Model``): Mamba-2 layers, with a shared block's
+    output added to the input of each site's layer."""
     B, S = tokens.shape
     decode = cache is not None
     positions = (
@@ -431,44 +507,25 @@ def forward_hybrid(
         if decode
         else jnp.broadcast_to(jnp.arange(S, dtype=jnp.int32)[None], (B, S))
     )
-    x = _embed(cfg, params, tokens)
-    shared = params["shared_attn"]
+    emb = _embed(cfg, params, tokens)
 
-    def group_body(carry, xs):
-        h, aux = carry
-        gssm, c_attn, c_ssm = xs
-        # shared attention block (weights shared; per-site KV cache)
-        h, nc_attn, a1 = _block_apply(
-            cfg, shared, h, positions, kind="attn",
-            cache=c_attn, cache_index=cache_index,
-        )
-        h, nc_ssm, a2 = _scan_stack(
-            cfg, gssm, h, positions, kind="ssm",
-            cache=c_ssm, cache_index=cache_index,
-        )
-        return (h, aux + a1 + a2), (nc_attn, nc_ssm)
+    def run_block(site, h, c):
+        return _shared_block(cfg, params, site, h, emb, positions, c, cache_index)
 
-    group_body = _maybe_remat(group_body, remat)
-    xs = (
-        params["group_ssm"],
-        cache["shared_attn"] if decode else None,
-        cache["group_ssm"] if decode else None,
-    )
-    (x, aux), (nca, ncs) = _scan(group_body, (x, jnp.zeros((), F32)), xs)
-    nct = None
-    if tail:
-        x, nct, a3 = _scan_stack(
-            cfg, params["tail_ssm"], x, positions, kind="ssm",
-            cache=cache["tail_ssm"] if decode else None,
-            cache_index=cache_index, remat=remat,
+    x = emb
+    new_mamba, new_kv = [], []
+    for j, (site, _) in enumerate(hybrid_plan(cfg)):
+        t = None
+        if site is not None:
+            t, nkv = _maybe_remat(functools.partial(run_block, site), remat)(
+                x, cache["kv"][site] if decode else None)
+            new_kv.append(nkv)
+        x, nm = _mamba_segment(
+            cfg, params["mamba"][j], x, t, cache["mamba"][j] if decode else None, remat,
         )
-        aux = aux + a3
-    new_cache = None
-    if decode:
-        new_cache = {"shared_attn": nca, "group_ssm": ncs}
-        if tail:
-            new_cache["tail_ssm"] = nct
-    return _logits(cfg, params, x, last_only), new_cache, aux
+        new_mamba.append(nm)
+    new_cache = {"mamba": new_mamba, "kv": new_kv} if decode else None
+    return _logits(cfg, params, x, last_only), new_cache, jnp.zeros((), F32)
 
 
 # --------------------------------------------------------------------------- #
@@ -709,16 +766,11 @@ def cache_descs(cfg: ModelConfig, batch: int, max_len: int) -> Dict:
     if cfg.family == "ssm":
         return {"layers": stack_tree(_ssm_cache_desc(cfg, batch), cfg.num_layers)}
     if cfg.family == "hybrid":
-        p = cfg.hybrid_attn_period
-        n_groups = cfg.num_layers // p
-        tail = cfg.num_layers - n_groups * p
-        out = {
-            "shared_attn": stack_tree(_attn_cache_desc(cfg, batch, max_len), n_groups),
-            "group_ssm": stack_tree(stack_tree(_ssm_cache_desc(cfg, batch), p), n_groups),
+        # each segment's SSM state stacked as its layers are; one KV cache a site
+        return {
+            "mamba": [stack_tree(_ssm_cache_desc(cfg, batch), n) for _, n in hybrid_plan(cfg)],
+            "kv": [_attn_cache_desc(cfg, batch, max_len) for _ in cfg.hybrid_layer_ids],
         }
-        if tail:
-            out["tail_ssm"] = stack_tree(_ssm_cache_desc(cfg, batch), tail)
-        return out
     if cfg.family == "encdec":
         return {
             "decoder": stack_tree(_attn_cache_desc(cfg, batch, max_len), cfg.num_layers),

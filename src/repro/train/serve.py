@@ -17,10 +17,14 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from ..core import LocalCluster, StateObject, VersionStore, spans
+from ..core import CrashedError, LocalCluster, StateObject, VersionStore, spans
 from ..models import cache_descs, decode_step, forward
 from ..models.config import ModelConfig
 from ..models.params import is_desc
+
+
+#: cache leaves that hold attention keys and values (the rest is recurrent state)
+_KV_LEAVES = ("k", "v", "ckv", "kpe")
 
 
 class DecodeSessionStateObject(StateObject):
@@ -36,6 +40,10 @@ class DecodeSessionStateObject(StateObject):
         self.extras = extras or {}
         self.tokens: List[int] = []
         self._cache = self._empty_cache()
+        #: bytes of the cache: attention keys and values, recurrent state
+        self._cache_bytes = {"kv_bytes": 0, "state_bytes": 0}
+        for path, leaf in jax.tree_util.tree_flatten_with_path(self._cache)[0]:
+            self._cache_bytes["kv_bytes" if path[-1].key in _KV_LEAVES else "state_bytes"] += leaf.nbytes
         self._step = jax.jit(
             lambda p, c, t, i: decode_step(cfg, p, c, t, i, extras=self.extras)
         )
@@ -48,14 +56,16 @@ class DecodeSessionStateObject(StateObject):
         )
 
     def _rebuild_cache(self) -> None:
-        """Replay surviving tokens to reconstruct the derived KV cache."""
+        """Replay surviving tokens to reconstruct the derived caches (KV
+        and recurrent state alike)."""
         self._cache = self._empty_cache()
-        tok = jnp.zeros((1, 1), jnp.int32)
-        for i, t in enumerate([0] + self.tokens[:-1] if self.tokens else []):
-            _, self._cache = self._step(
-                self.params, self._cache,
-                jnp.asarray([[t]], jnp.int32), jnp.asarray(i, jnp.int32),
-            )
+        with spans.span("session.rebuild", so_id=self.runtime.so_id, tokens=len(self.tokens),
+                        **self._cache_bytes):
+            for i, t in enumerate([0] + self.tokens[:-1] if self.tokens else []):
+                _, self._cache = self._step(
+                    self.params, self._cache,
+                    jnp.asarray([[t]], jnp.int32), jnp.asarray(i, jnp.int32),
+                )
 
     # -- persistence -----------------------------------------------------
     def Persist(self, version: int, metadata: bytes, callback: Callable[[], None]) -> None:
@@ -70,7 +80,15 @@ class DecodeSessionStateObject(StateObject):
 
         self.spawn_io(_io)
 
+    def _released(self) -> bool:
+        """True once ``on_crash`` has let go of the weights and caches. It
+        does so under the exclusive epoch, so inside an action or a
+        ``Restore`` the answer holds until the action ends."""
+        return self.params is None
+
     def Restore(self, version: int) -> bytes:
+        if self._released():
+            raise CrashedError(f"{self.runtime.so_id}: this incarnation has crashed")
         payload, meta = self.store.read(version)
         self.tokens = np.frombuffer(payload, np.int32).tolist()
         self._rebuild_cache()
@@ -85,8 +103,13 @@ class DecodeSessionStateObject(StateObject):
     def on_crash(self) -> None:
         self.store.poison()
         self.store.drop_memory()
-        self.tokens = []
-        self._cache = self._empty_cache()
+        # this incarnation is never used again: it lets go of the device
+        # memory it holds (the weights are shared; the caches are its own),
+        # once a step in flight has ended, so no step sees them go
+        with self.runtime.exclusive_epoch():
+            self.tokens = []
+            self._cache = None
+            self.params = None
 
     # -- service API -------------------------------------------------------
     def generate(self, n: int) -> Optional[List[int]]:
@@ -96,6 +119,9 @@ class DecodeSessionStateObject(StateObject):
         for _ in range(n):
             if not self.StartAction(None):
                 return None
+            if self._released():  # killed while this call waited for the action
+                self.runtime.abort_action()
+                raise CrashedError(f"{so_id}: this incarnation has crashed")
             idx = len(self.tokens)
             if idx >= self.max_len:
                 self.EndAction()
